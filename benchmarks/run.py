@@ -8,11 +8,14 @@ import argparse
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from benchmarks import paper_tables, kernel_bench, mc_bench
 

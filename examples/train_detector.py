@@ -25,6 +25,7 @@ import jax
 from repro.configs import yolo_irc
 from repro.core import NonidealConfig
 from repro.data.detection import SyntheticDetectionData
+from repro.launch.compile_cache import enable_compile_cache
 from repro.mc import McConfig, run_ablation_detector
 from repro.models import IRCDetector
 from repro.obs import NULL_RUNLOG, PhaseTimer, maybe_runlog, timed_step
@@ -113,6 +114,7 @@ def main():
     ap.add_argument("--trace", action="store_true",
                     help="capture a jax.profiler trace into the run dir")
     args = ap.parse_args()
+    enable_compile_cache()
 
     obs = maybe_runlog(bool(args.run_dir), "train-detector",
                        args=vars(args), root=args.run_dir,

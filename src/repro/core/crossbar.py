@@ -40,7 +40,9 @@ from repro.core.ternary import (ternary_quantize, binary_quantize,
 def _block_reduce(x_ext: jax.Array, plane: jax.Array, block: int
                   ) -> jax.Array:
     """Per-IR-block partial currents: x_ext [..., R], plane [R, N]
-    -> [..., nb, N] with nb = ceil(R / block)."""
+    -> [..., nb, N] with nb = ceil(R / block).  Full f32 (`HIGHEST`): the
+    planes carry variation-scaled conductances that a default-precision
+    TPU matmul would round to bf16."""
     rows, n_out = plane.shape
     nb = -(-rows // block)
     pad = nb * block - rows
@@ -49,7 +51,8 @@ def _block_reduce(x_ext: jax.Array, plane: jax.Array, block: int
         plane = jnp.pad(plane, ((0, pad), (0, 0)))
     xb = x_ext.reshape(x_ext.shape[:-1] + (nb, block))
     pb = plane.reshape(nb, block, n_out)
-    return jnp.einsum("...bk,bkn->...bn", xb, pb)
+    return jnp.einsum("...bk,bkn->...bn", xb, pb,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def _accumulate(blocks: jax.Array, counts: jax.Array, cfg: ni.NonidealConfig,

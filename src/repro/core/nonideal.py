@@ -120,22 +120,30 @@ def ir_drop_factors(block_currents: jax.Array, alpha: float,
     The cumulative wire drop is LINEAR in the block currents:
       cum_wire[b] = sum_{k=1..b} suffix_sum(I)_k = sum_j min(b, j) * I_j,
     so it is one [nb, nb] min-matrix contraction instead of the
-    reverse-cumsum-cumsum chain (XLA lowers cumsum miserably on CPU, and the
+    reverse-cumsum-cumsum chain (XLA lowers cumsum miserably on CPU, the
     flips/transposes cost full passes over the [chips, batch, blocks, cols]
-    tensors of `repro.mc`).  The `axis` argument lets batched callers keep
-    the block axis wherever their layout puts it.
+    tensors of `repro.mc`, and Pallas TPU has no `rev`).  The min matrix is
+    symmetric, so each layout is a plain matmul; the Pallas kernel calls
+    this function on its [nb, bn] tile slabs.  The `axis` argument lets
+    batched callers keep the block axis wherever their layout puts it.
+
+    The contraction runs at `Precision.HIGHEST`: block currents carry
+    variation-scaled conductances, and a default-precision TPU matmul would
+    round them to bf16 — the same order as the variation being simulated.
     """
     nb = block_currents.shape[axis]
-    idx = jnp.arange(nb, dtype=jnp.float32)
-    w_min = jnp.minimum(idx[:, None], idx[None, :])        # [j, b] -> min(b, j)
+    row = jax.lax.broadcasted_iota(jnp.int32, (nb, nb), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (nb, nb), 1)
+    w_min = jnp.minimum(row, col).astype(jnp.float32)      # min(b, j)
+    mm = lambda a, b: jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
     ax = axis % block_currents.ndim
     if ax == block_currents.ndim - 1:
-        cum = jnp.einsum("...j,jb->...b", block_currents, w_min)
+        cum = mm(block_currents, w_min)
     elif ax == block_currents.ndim - 2:
-        cum = jnp.einsum("...jn,jb->...bn", block_currents, w_min)
+        cum = mm(w_min, block_currents)
     else:
         moved = jnp.moveaxis(block_currents, ax, -1)
-        cum = jnp.moveaxis(jnp.einsum("...j,jb->...b", moved, w_min), -1, ax)
+        cum = jnp.moveaxis(mm(moved, w_min), -1, ax)
     return jnp.clip(1.0 - alpha * cum, 0.0, 1.0)
 
 

@@ -55,7 +55,8 @@ DEFAULT_CANDIDATES: Tuple[Tuple[int, int, int], ...] = (
 
 def problem_key(C: int, M: int, N: int, K: int,
                 backend: Optional[str] = None) -> str:
-    backend = backend or jax.default_backend()
+    from repro.kernels.ops import target_platform
+    backend = backend or target_platform()
     return f"{backend}/c{C}_m{M}_n{N}_k{K}"
 
 

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -100,7 +99,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, 1), jnp.float32),       # running max
             pltpu.VMEM((bq, 1), jnp.float32),       # running denominator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

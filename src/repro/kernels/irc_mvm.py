@@ -4,11 +4,18 @@ This is the compute hot spot of the structural simulation (paper Secs. III-IV):
 for each (batch, output-channel) tile it computes, entirely in VMEM,
 
   1. per-32-row-sub-block partial currents for both conductance planes
-     (the IR-drop block model needs them individually) — MXU batched dots;
+     (the IR-drop block model needs them individually) — one MXU dot per
+     block on a static 32-lane slice of the word-line tile;
   2. activated-LRS counts per plane — two MXU dots;
-  3. the fused epilogue: IR-drop suffix-cumsum weighting, the paper's
-     piecewise-quartic accumulation nonlinearity, differential SA comparison
-     with offset noise and limited-sensing-range fallback — all VPU.
+  3. the fused epilogue: IR-drop weighting (the min-matrix contraction of
+     `repro.core.nonideal.ir_drop_factors`, one small MXU dot per tile
+     row), the paper's piecewise-quartic accumulation nonlinearity,
+     differential SA comparison with offset noise and limited-sensing-range
+     fallback.
+
+Every dot runs at `Precision.HIGHEST` (full f32): the planes carry
+variation-scaled conductances, and bf16 rounding is the same order as the
+variation being simulated.
 
 A naive jnp composition round-trips [B, n_blocks, N] block currents and the
 count/current tensors through HBM ~10 times; the kernel keeps everything in
@@ -17,9 +24,10 @@ binary output.
 
 Tiling: grid = (B/bm, N/bn, R/bk) with the R walk innermost ("arbitrary"
 semantics, accumulation in scratch).  Defaults bm=8 (sublane), bn=128
-(lane), bk=256 (8 IR blocks / MXU-friendly contraction) — sweepable; VMEM
-footprint at defaults is <1 MB, and all matmul dims are multiples of
-(8, 128) for MXU alignment.
+(lane), bk=256 (8 IR blocks per step) — sweepable.  VMEM scratch is two
+(R/32, bm, bn) block-current buffers plus four (bm, bn) tiles: at
+most 0.72 MB at the detector's R = 572 for every autotune candidate, compiled
+for v5e by tests/test_tpu_compile.py.
 
 Stochastic terms (SA offset noise, unresolvable-comparison fallback bits)
 are pre-sampled inputs, so the kernel is deterministic and exactly testable
@@ -34,8 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
+from repro.core.nonideal import ir_drop_factors
 from repro.kernels.ref import IrcEpilogueParams, _NL_LO, _NL_HI
 
 
@@ -54,44 +61,41 @@ def _nl_ratio_inline(p: jax.Array) -> jax.Array:
 def _accum_step(x, ep, en, gp, gn, blocks_p, blocks_n, p_pos, p_neg,
                 k, nbk, blk):
     """One R-walk step: full-tile count dots + per-IR-block partial-current
-    dots, accumulated into the VMEM scratch (shared by both kernels)."""
-    bm = x.shape[0]
-    bn = ep.shape[1]
+    dots, accumulated into the VMEM scratch (shared by both kernels).
 
-    # activated-LRS counts: full-tile MXU dots
-    dot = functools.partial(jax.lax.dot_general,
-                            dimension_numbers=(((1,), (0,)), ((), ())),
+    Each IR block is a static 32-lane slice of the word-line tile against
+    the matching (sublane-aligned) 32 plane rows: Mosaic lowers these
+    slices, where splitting the lane axis by reshape is refused.  Every dot
+    runs at `HIGHEST`, i.e. in full f32 on the MXU."""
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
     p_pos[...] += dot(x, gp)
     p_neg[...] += dot(x, gn)
-
-    # per-IR-block partial currents: batched MXU dots over the 32-row blocks
-    xb = x.reshape(bm, nbk, blk).transpose(1, 0, 2)       # (nbk, bm, 32)
-    epb = ep.reshape(nbk, blk, bn)
-    enb = en.reshape(nbk, blk, bn)
-    bdot = functools.partial(
-        jax.lax.dot_general,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    blocks_p[pl.ds(k * nbk, nbk)] = bdot(xb, epb)         # (nbk, bm, bn)
-    blocks_n[pl.ds(k * nbk, nbk)] = bdot(xb, enb)
+    for j in range(nbk):
+        cols = slice(j * blk, (j + 1) * blk)
+        blocks_p[k * nbk + j] = dot(x[:, cols], ep[cols])    # (bm, bn)
+        blocks_n[k * nbk + j] = dot(x[:, cols], en[cols])
 
 
-def _epilogue_tile(blocks_p, blocks_n, pp, pn, eps, rnd,
+def _line_currents(blocks, line, params: IrcEpilogueParams) -> None:
+    """Bit-line currents of one (bm, bn) tile from its per-block currents
+    `blocks` (NBT, bm, bn), written into `line` (bm, bn).  With IR drop each
+    tile row's [NBT, bn] slab is weighted by `ir_drop_factors` — the
+    min-matrix contraction the structural simulation uses."""
+    if not params.apply_ir:
+        line[...] = jnp.sum(blocks[...], axis=0)
+        return
+    for m in range(line.shape[0]):
+        slab = blocks[:, m, :]                                # (NBT, bn)
+        slab = slab * ir_drop_factors(slab, params.ir_alpha, axis=-2)
+        line[pl.ds(m, 1), :] = jnp.sum(slab, axis=0, keepdims=True)
+
+
+def _epilogue_tile(i_pos, i_neg, pp, pn, eps, rnd,
                    params: IrcEpilogueParams) -> jax.Array:
-    """Fused VPU epilogue on one (bm, bn) tile: IR-drop weighting,
-    accumulation nonlinearity, SA comparison + sensing-range fallback."""
-    def line(blocks):                                     # (NBT, bm, bn)
-        if params.apply_ir:
-            rev = blocks[::-1]
-            suffix = jnp.cumsum(rev, axis=0)[::-1]
-            cum = jnp.cumsum(suffix, axis=0) - suffix[0:1]
-            factors = jnp.clip(1.0 - params.ir_alpha * cum, 0.0, 1.0)
-            return jnp.sum(blocks * factors, axis=0)
-        return jnp.sum(blocks, axis=0)
-
-    i_pos = line(blocks_p)
-    i_neg = line(blocks_n)
+    """Fused VPU epilogue on one (bm, bn) tile of IR-dropped bit-line
+    currents: accumulation nonlinearity, SA comparison + sensing-range
+    fallback."""
     if params.apply_nonlinearity:
         i_pos = i_pos * _nl_ratio_inline(pp)
         i_neg = i_neg * _nl_ratio_inline(pn)
@@ -112,8 +116,17 @@ def _epilogue_tile(blocks_p, blocks_n, pp, pn, eps, rnd,
     return out
 
 
+def _finish(blocks_p, blocks_n, line_p, line_n, p_pos, p_neg, eps, rnd,
+            params: IrcEpilogueParams) -> jax.Array:
+    """Last R step of a tile: IR-dropped line currents, then the epilogue."""
+    _line_currents(blocks_p, line_p, params)
+    _line_currents(blocks_n, line_n, params)
+    return _epilogue_tile(line_p[...], line_n[...], p_pos[...], p_neg[...],
+                          eps, rnd, params)
+
+
 def _irc_mvm_kernel(x_ref, ep_ref, en_ref, gp_ref, gn_ref, eps_ref, rnd_ref,
-                    out_ref, blocks_p, blocks_n, p_pos, p_neg,
+                    out_ref, blocks_p, blocks_n, line_p, line_n, p_pos, p_neg,
                     *, params: IrcEpilogueParams, nk: int, bk: int):
     k = pl.program_id(2)
     blk = params.ir_block
@@ -121,8 +134,8 @@ def _irc_mvm_kernel(x_ref, ep_ref, en_ref, gp_ref, gn_ref, eps_ref, rnd_ref,
 
     @pl.when(k == 0)
     def _init():
-        blocks_p[...] = jnp.zeros_like(blocks_p)
-        blocks_n[...] = jnp.zeros_like(blocks_n)
+        # every IR block slot is written by exactly one R step; only the
+        # count accumulators need zeroing
         p_pos[...] = jnp.zeros_like(p_pos)
         p_neg[...] = jnp.zeros_like(p_neg)
 
@@ -135,15 +148,16 @@ def _irc_mvm_kernel(x_ref, ep_ref, en_ref, gp_ref, gn_ref, eps_ref, rnd_ref,
 
     @pl.when(k == nk - 1)
     def _epilogue():
-        out_ref[...] = _epilogue_tile(blocks_p[...], blocks_n[...],
-                                      p_pos[...], p_neg[...],
-                                      eps_ref[...], rnd_ref[...], params)
+        out_ref[...] = _finish(blocks_p, blocks_n, line_p, line_n,
+                               p_pos, p_neg, eps_ref[...], rnd_ref[...],
+                               params)
 
 
 def _irc_mvm_chips_kernel(x_ref, ep_ref, en_ref, gp_ref, gn_ref, eps_ref,
-                          rnd_ref, out_ref, blocks_p, blocks_n, p_pos, p_neg,
-                          *, params: IrcEpilogueParams, nk: int, bk: int,
-                          shared_counts: bool, per_chip_x: bool):
+                          rnd_ref, out_ref, blocks_p, blocks_n, line_p,
+                          line_n, p_pos, p_neg, *, params: IrcEpilogueParams,
+                          nk: int, bk: int, shared_counts: bool,
+                          per_chip_x: bool):
     """Chip-batched variant: grid (chips, B/bm, N/bn, R/bk); the plane /
     periphery refs carry a leading length-1 chip block.  The word-line tile
     is SHARED by every chip by default (one ensemble evaluates one input
@@ -159,8 +173,8 @@ def _irc_mvm_chips_kernel(x_ref, ep_ref, en_ref, gp_ref, gn_ref, eps_ref,
 
     @pl.when(k == 0)
     def _init():
-        blocks_p[...] = jnp.zeros_like(blocks_p)
-        blocks_n[...] = jnp.zeros_like(blocks_n)
+        # every IR block slot is written by exactly one R step; only the
+        # count accumulators need zeroing
         p_pos[...] = jnp.zeros_like(p_pos)
         p_neg[...] = jnp.zeros_like(p_neg)
 
@@ -176,9 +190,8 @@ def _irc_mvm_chips_kernel(x_ref, ep_ref, en_ref, gp_ref, gn_ref, eps_ref,
 
     @pl.when(k == nk - 1)
     def _epilogue():
-        out_ref[0] = _epilogue_tile(blocks_p[...], blocks_n[...],
-                                    p_pos[...], p_neg[...],
-                                    eps_ref[0], rnd_ref[0], params)
+        out_ref[0] = _finish(blocks_p, blocks_n, line_p, line_n,
+                             p_pos, p_neg, eps_ref[0], rnd_ref[0], params)
 
 
 def irc_mvm_pallas(x: jax.Array, ep: jax.Array, en: jax.Array,
@@ -216,10 +229,12 @@ def irc_mvm_pallas(x: jax.Array, ep: jax.Array, en: jax.Array,
         scratch_shapes=[
             pltpu.VMEM((nbt, bm, bn), jnp.float32),   # blocks_p
             pltpu.VMEM((nbt, bm, bn), jnp.float32),   # blocks_n
+            pltpu.VMEM((bm, bn), jnp.float32),        # line_p
+            pltpu.VMEM((bm, bn), jnp.float32),        # line_n
             pltpu.VMEM((bm, bn), jnp.float32),        # p_pos
             pltpu.VMEM((bm, bn), jnp.float32),        # p_neg
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, ep, en, gp, gn, eps_sa, rnd_bits)
@@ -276,10 +291,12 @@ def irc_mvm_chips_pallas(x: jax.Array, ep: jax.Array, en: jax.Array,
         scratch_shapes=[
             pltpu.VMEM((nbt, bm, bn), jnp.float32),   # blocks_p
             pltpu.VMEM((nbt, bm, bn), jnp.float32),   # blocks_n
+            pltpu.VMEM((bm, bn), jnp.float32),        # line_p
+            pltpu.VMEM((bm, bn), jnp.float32),        # line_n
             pltpu.VMEM((bm, bn), jnp.float32),        # p_pos
             pltpu.VMEM((bm, bn), jnp.float32),        # p_neg
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,8 +1,11 @@
 """Public jit'd entry points for the Pallas kernels (padding + dispatch).
 
-On CPU (this container) the kernels execute in interpret mode; on TPU they
-compile to Mosaic.  Shapes are padded to tile multiples here so callers can
-pass arbitrary layer shapes.
+On CPU the kernels execute in interpret mode; on TPU they compile to
+Mosaic.  The choice follows the platform the call is traced for — the
+`jax.default_device` override when one is set (it is part of the jit key),
+else the default backend — so a TPU host can run the same call on its CPU
+device for a cross-check.  Shapes are padded to tile multiples here so
+callers can pass arbitrary layer shapes.
 """
 from __future__ import annotations
 
@@ -20,8 +23,16 @@ from repro.kernels.ternary_matmul import ternary_matmul_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 
 
+def target_platform() -> str:
+    """Platform the computation being traced will run on."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform
+
+
 def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+    return target_platform() == "cpu"
 
 
 def _pad_to(a: jax.Array, axis: int, mult: int) -> jax.Array:

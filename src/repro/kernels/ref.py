@@ -17,6 +17,12 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.core.nonideal import ir_drop_factors
+
+# physics contractions run in full f32 on every backend (a default-precision
+# TPU matmul rounds its f32 operands to bf16)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass(frozen=True)
 class IrcEpilogueParams:
@@ -66,7 +72,9 @@ def _line_current(x: jax.Array, eplane: jax.Array, ep_: IrcEpilogueParams
     """Accumulate one plane with the IR-drop block model.
     x [B,R], eplane [R,N] -> [B,N].  R is padded up to a multiple of the IR
     block size; appended zero rows sit at the far end of the bit-line and
-    carry no current, so the drop factors of real blocks are unchanged."""
+    carry no current, so the drop factors of real blocks are unchanged.
+    The drop factors come from `repro.core.nonideal.ir_drop_factors`, the
+    one source the kernel and the structural simulation also use."""
     pad = (-x.shape[1]) % ep_.ir_block
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad)))
@@ -76,13 +84,10 @@ def _line_current(x: jax.Array, eplane: jax.Array, ep_: IrcEpilogueParams
     nb = R // ep_.ir_block
     xb = x.reshape(B, nb, ep_.ir_block)
     pb = eplane.reshape(nb, ep_.ir_block, N)
-    blocks = jnp.einsum("bik,ikn->bin", xb, pb)          # [B, nb, N]
+    blocks = jnp.einsum("bik,ikn->bin", xb, pb,
+                        precision=_HIGHEST)               # [B, nb, N]
     if ep_.apply_ir:
-        bl = jnp.moveaxis(blocks, 1, 2)                   # [B, N, nb]
-        suffix = jnp.cumsum(bl[..., ::-1], axis=-1)[..., ::-1]
-        cum = jnp.cumsum(suffix, axis=-1) - suffix[..., 0:1]
-        factors = jnp.clip(1.0 - ep_.ir_alpha * cum, 0.0, 1.0)
-        blocks = blocks * jnp.moveaxis(factors, 2, 1)
+        blocks = blocks * ir_drop_factors(blocks, ep_.ir_alpha, axis=-2)
     return jnp.sum(blocks, axis=1)
 
 
@@ -101,8 +106,8 @@ def irc_mvm_ref(x: jax.Array, ep: jax.Array, en: jax.Array,
     x = x.astype(jnp.float32)
     i_pos = _line_current(x, ep.astype(jnp.float32), params)
     i_neg = _line_current(x, en.astype(jnp.float32), params)
-    p_pos = x @ gp.astype(jnp.float32)
-    p_neg = x @ gn.astype(jnp.float32)
+    p_pos = jnp.matmul(x, gp.astype(jnp.float32), precision=_HIGHEST)
+    p_neg = jnp.matmul(x, gn.astype(jnp.float32), precision=_HIGHEST)
     if params.apply_nonlinearity:
         i_pos = i_pos * nl_ratio(p_pos)
         i_neg = i_neg * nl_ratio(p_neg)

@@ -15,6 +15,9 @@ import os
 
 os.environ["XLA_FLAGS"] = (os.environ.get("_REPRO_EXTRA_XLA", "") +
                            " --xla_force_host_platform_device_count=512")
+# a CPU-only tool: this process and every JAX child it spawns (they inherit
+# the environment) stay off the accelerator, which belongs to one process
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # ---- nothing above this line may import jax ----
 import argparse

@@ -62,6 +62,8 @@ import dataclasses
 import json
 from pathlib import Path
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def build_layer(args):
     import jax
@@ -429,6 +431,7 @@ def main() -> None:
     ap.add_argument("--trace", action="store_true",
                     help="capture a jax.profiler trace into the run dir")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.network == "detector":
         # layer-only knobs have no detector equivalent: fail loudly rather

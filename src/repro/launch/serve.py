@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.ckpt import CheckpointManager
 from repro.configs.registry import get_config, list_archs
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _build_parser() -> argparse.ArgumentParser:
@@ -175,6 +176,7 @@ def main():
     ap = _build_parser()
     args = ap.parse_args()
     _check_flag_use(ap, args)
+    enable_compile_cache()
 
     from repro.obs import maybe_runlog
     name = ("serve-detector" if args.network == "detector"

@@ -22,6 +22,7 @@ from jax.sharding import NamedSharding
 
 from repro.configs.registry import get_config, list_archs
 from repro.data import SyntheticLMData
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import LM
 from repro.models.lm_config import IRCMode
 from repro.optim import AdamWConfig
@@ -63,6 +64,7 @@ def main():
     ap.add_argument("--trace", action="store_true",
                     help="capture a jax.profiler trace into the run dir")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.obs import maybe_runlog
     obs = maybe_runlog(bool(args.run_dir), f"train-{args.arch}",

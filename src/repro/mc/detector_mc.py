@@ -345,14 +345,17 @@ def run_mc_detector(key: jax.Array, det, params, images: jax.Array,
                 use_kernel=use_kernel, kernel_impl=kernel_impl,
                 device=mc.device)
 
-        inflight = dispatch(chunk_ids[0]) if chunk_ids else None
-
+    inflight = None
     n_done = 0
     for chunk_i, ids in enumerate(chunk_ids):
         n_chunk = int(ids.shape[0])
         with timer.lap(items=n_chunk):
             if pipeline:
                 with dev_timer.lap(items=n_chunk):
+                    if inflight is None:
+                        # first chunk: its jit compile is part of the
+                        # first (compile) lap
+                        inflight = dispatch(ids)
                     preds_dev = jax.block_until_ready(inflight)
                 if chunk_i + 1 < len(chunk_ids):
                     # double buffer: next chunk on device DURING host scoring

@@ -461,6 +461,26 @@ class IRCDetector:
         return params
 
     # ------------------------------------------------------------ forward
+    def stem(self, params: PyTree, images: jax.Array, *,
+             mode: str = "eval") -> jax.Array:
+        """Digital stem: 3x3/2 conv + BN + binary activation -> the {0,1}
+        [B, H/2, W/2, stage_channels[0]] input of the first IRC layer."""
+        cfg = self.cfg
+        x = jax.lax.conv_general_dilated(
+            images.astype(cfg.dtype), params["stem"], (2, 2), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        bn = params["stem_bn"]
+        if mode in ("train", "train_ensemble"):
+            mu = jnp.mean(x, axis=(0, 1, 2))
+            var = jnp.var(x, axis=(0, 1, 2))
+        else:
+            # eval/ensemble: running stats from `calibrate_bn` — batch
+            # statistics here would make deployed outputs depend on batch
+            # composition (and MC chunking would change the metric)
+            mu, var = bn["mean"], bn["var"]
+        x = bn["gamma"] * (x - mu) / jnp.sqrt(var + 1e-5) + bn["beta"]
+        return binary_activation(x)
+
     def apply(self, params: PyTree, images: jax.Array, *, mode: str = "train",
               key: Optional[jax.Array] = None,
               cfg_ni: ni.NonidealConfig = ni.NonidealConfig.none(),
@@ -492,20 +512,7 @@ class IRCDetector:
         """
         cfg = self.cfg
         key = key if key is not None else jax.random.PRNGKey(0)
-        x = jax.lax.conv_general_dilated(
-            images.astype(cfg.dtype), params["stem"], (2, 2), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        bn = params["stem_bn"]
-        if mode in ("train", "train_ensemble"):
-            mu = jnp.mean(x, axis=(0, 1, 2))
-            var = jnp.var(x, axis=(0, 1, 2))
-        else:
-            # eval/ensemble: running stats from `calibrate_bn` — batch
-            # statistics here would make deployed outputs depend on batch
-            # composition (and MC chunking would change the metric)
-            mu, var = bn["mean"], bn["var"]
-        x = bn["gamma"] * (x - mu) / jnp.sqrt(var + 1e-5) + bn["beta"]
-        x = binary_activation(x)
+        x = self.stem(params, images, mode=mode)
 
         for s, (ch, nb) in enumerate(zip(cfg.stage_channels,
                                          cfg.blocks_per_stage)):

@@ -6,7 +6,7 @@ speaks this one telemetry format.  A run is a directory:
 
   experiments/<run_id>/
     manifest.json     provenance: argv/args, git SHA, jax/jaxlib versions,
-                      host, backend, device count, timestamps
+                      host, platform, device kind and count, timestamps
     metrics.jsonl     append-only event stream; one JSON object per line,
                       each with a monotonic `t` (seconds since run start)
                       and a `kind` ("chunk", "convergence", "phase", ...)
@@ -48,25 +48,29 @@ def git_sha() -> Optional[str]:
 
 
 def collect_env() -> Dict[str, Any]:
-    """Host / toolchain metadata: what makes machine-relative numbers
-    interpretable across machines (also merged into BENCH_mc.json)."""
+    """Host / toolchain / device metadata: what makes machine-relative
+    numbers interpretable across machines (also merged into BENCH_mc.json).
+
+    `platform`, `device_kind` and `device_count` are what JAX reports for
+    the devices the run used; a JAX failure here propagates, so no run can
+    record a device it did not see."""
     import platform
     import socket
-    info: Dict[str, Any] = {
+
+    import jax
+    import jaxlib
+    dev = jax.devices()[0]
+    return {
         "host": socket.gethostname(),
-        "platform": platform.platform(),
+        "os": platform.platform(),
         "python": platform.python_version(),
         "cpu_count": os.cpu_count(),
+        "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+        "backend": jax.default_backend(),
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "device_count": jax.device_count(),
     }
-    try:
-        import jax
-        import jaxlib
-        info.update({"jax": jax.__version__, "jaxlib": jaxlib.__version__,
-                     "backend": jax.default_backend(),
-                     "device_count": jax.device_count()})
-    except Exception:       # pragma: no cover - jax is a hard dep in practice
-        pass
-    return info
 
 
 def _jsonable(v):
@@ -164,25 +168,21 @@ class RunLog:
     # -------------------------------------------------------------- tracing
 
     def start_trace(self) -> bool:
-        """Capture a `jax.profiler` trace into `<run_dir>/trace/`."""
-        try:
-            import jax
-            jax.profiler.start_trace(str(self.path / "trace"))
-            self._tracing = True
-        except Exception as e:   # profiler backends vary across jax versions
-            self.log_event("trace_error", error=f"{type(e).__name__}: {e}")
-            self._tracing = False
-        return self._tracing
+        """Capture a `jax.profiler` trace into `<run_dir>/trace/`.  A
+        profiler failure raises: a run that asked for a trace never ends
+        without one."""
+        import jax
+        jax.profiler.start_trace(str(self.path / "trace"))
+        self._tracing = True
+        return True
 
     def stop_trace(self) -> None:
+        """Stop the trace started by `start_trace` (raises on failure)."""
         if not self._tracing:
             return
-        try:
-            import jax
-            jax.profiler.stop_trace()
-        except Exception as e:
-            self.log_event("trace_error", error=f"{type(e).__name__}: {e}")
+        import jax
         self._tracing = False
+        jax.profiler.stop_trace()
 
     # ------------------------------------------------------------- finalize
 

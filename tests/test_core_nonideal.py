@@ -108,6 +108,29 @@ class TestIRDrop:
         f = ir_drop_factors(blocks, DEFAULT_MACRO.ir_alpha)
         assert float(f[0]) == pytest.approx(1.0)
 
+    # nb: the IR-block counts of the detector's 572-row group crossbars, as
+    # is (18) and padded by the kernel to bk = 128, 256, 512 (20, 24, 32);
+    # shape/axis: every layout branch (last, second-to-last, leading axis)
+    @pytest.mark.parametrize("nb", [18, 20, 24, 32])
+    @pytest.mark.parametrize("shape,axis", [((3, None), -1),
+                                            ((None, 5), -2),
+                                            ((None, 2, 3), 0)])
+    def test_values_match_suffix_cumsum(self, nb, shape, axis):
+        # the wire segment feeding block k carries the suffix sum of the
+        # block currents; block b sees the drop of segments 1..b
+        shape = tuple(nb if d is None else d for d in shape)
+        rng = np.random.default_rng(nb)
+        blocks = rng.uniform(0.0, 32.0, shape)
+        alpha = 4 * DEFAULT_MACRO.ir_alpha
+        moved = np.moveaxis(blocks, axis, -1)
+        suffix = np.cumsum(moved[..., ::-1], axis=-1)[..., ::-1]
+        cum = np.cumsum(suffix, axis=-1) - suffix[..., :1]
+        want = np.moveaxis(np.clip(1.0 - alpha * cum, 0.0, 1.0), -1, axis)
+        got = ir_drop_factors(jnp.asarray(blocks, jnp.float32), alpha,
+                              axis=axis)
+        assert 0.0 < want.min() < want.max() <= 1.0   # no clipping at 0
+        np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=2e-6)
+
 
 class TestSA:
     def test_required_diff_grows_with_p(self):

@@ -44,6 +44,19 @@ class TestEvalPathFixes:
         out1 = det.apply(params, imgs[:1], mode="eval", key=key)
         np.testing.assert_array_equal(np.asarray(out8[:1]), np.asarray(out1))
 
+    def test_stem_feeds_first_irc_layer(self):
+        """`stem` is the {0,1} input of the first IRC layer: running stats
+        at eval (batch-invariant), batch statistics in train mode."""
+        det, params = _detector("ternary")
+        imgs = jax.random.uniform(jax.random.PRNGKey(2), (4, 32, 32, 3))
+        x = det.stem(params, imgs)
+        assert x.shape == (4, 16, 16, det.cfg.stage_channels[0])
+        assert set(np.unique(np.asarray(x))) <= {0.0, 1.0}
+        np.testing.assert_array_equal(np.asarray(det.stem(params, imgs[:1])),
+                                      np.asarray(x[:1]))
+        xt = det.stem(params, imgs[:1], mode="train")
+        assert not np.array_equal(np.asarray(xt), np.asarray(x[:1]))
+
     def test_calibrate_bn_populates_stem_stats_both_designs(self):
         for scheme in ("ternary", "binary"):
             cfg = yolo_irc.smoke(scheme)

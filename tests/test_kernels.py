@@ -129,6 +129,26 @@ class TestIrcMvmKernel:
         assert float(jnp.mean(core_out == kern_out)) > 0.995
 
 
+class TestTargetPlatform:
+    def test_follows_default_device(self):
+        """A TPU host runs a kernel on its CPU device under
+        `jax.default_device(cpu)`; interpret mode must follow that device."""
+        from repro.kernels.ops import target_platform
+        assert target_platform() == jax.default_backend()
+        with jax.default_device(jax.devices("cpu")[0]):
+            assert target_platform() == "cpu"
+        with jax.default_device("cpu"):
+            assert target_platform() == "cpu"
+
+    def test_interpret_call_under_cpu_default_device(self):
+        args = _mk_inputs(8, 160, 24, seed=4)
+        params = IrcEpilogueParams()
+        with jax.default_device(jax.devices("cpu")[0]):
+            out = irc_mvm(*args, params)
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.asarray(irc_mvm_ref(*args, params)))
+
+
 class TestTernaryMatmulKernel:
     @pytest.mark.parametrize("shape", [(1, 16, 1), (33, 300, 77),
                                        (128, 512, 128), (200, 1000, 40)])

@@ -81,8 +81,31 @@ class TestRunLog:
 
     def test_collect_env_has_toolchain(self):
         env = collect_env()
-        for k in ("host", "python", "cpu_count", "jax", "jaxlib", "backend"):
+        for k in ("host", "python", "cpu_count", "jax", "jaxlib", "backend",
+                  "platform", "device_kind", "device_count"):
             assert k in env
+        dev = jax.devices()[0]
+        assert env["platform"] == dev.platform
+        assert env["device_kind"] == dev.device_kind
+        assert env["device_count"] == jax.device_count()
+
+    def test_collect_env_propagates_jax_errors(self, monkeypatch):
+        def broken():
+            raise RuntimeError("no backend")
+        monkeypatch.setattr(jax, "devices", broken)
+        with pytest.raises(RuntimeError, match="no backend"):
+            collect_env()
+
+    def test_trace_failure_raises(self, tmp_path, monkeypatch):
+        """A run that asked for a trace must not end without one."""
+        rl = RunLog.create("t", root=str(tmp_path), run_id="r")
+
+        def broken(*_a, **_k):
+            raise RuntimeError("profiler unavailable")
+        monkeypatch.setattr(jax.profiler, "start_trace", broken)
+        with pytest.raises(RuntimeError, match="profiler unavailable"):
+            rl.start_trace()
+        assert not rl._tracing
 
 
 # ------------------------------------------------------------- PhaseTimer

@@ -122,3 +122,20 @@ class TestDryRunIntegration:
         assert rec["status"] == "ok"
         assert rec["devices"] == 512
         assert rec["cost_analysis"].get("flops", 0) > 0
+
+
+class TestHostMesh:
+    def test_axes_are_auto(self):
+        """`with_sharding_constraint` and the chips placement need Auto axes
+        (jax.make_mesh defaults to Explicit)."""
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh()
+        assert mesh.axis_names == ("data",)
+        assert mesh.axis_types == (jax.sharding.AxisType.Auto,)
+        assert mesh.devices.size == len(jax.devices())
+
+    def test_explicit_device_subset(self):
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(jax.devices()[:1])
+        assert mesh.devices.size == 1
+        assert mesh.devices.flat[0] == jax.devices()[0]
