@@ -9,6 +9,11 @@ Each entry point (`launch.mc`, `launch.serve`, `launch.train`,
   * otherwise: `<checkout>/.jax_cache` (git-ignored).  The directory is part
     of the cache key, so it is fixed — never derived from a temporary name,
     a pid or the time — and a later run in the same checkout hits it.
+
+Either way the key includes the programs' metadata, their `jax.named_scope`
+paths and source locations among it: without them a build whose scopes
+differ loads an executable compiled from another build, whose ops carry
+that build's op names into a profiler trace.
 """
 from __future__ import annotations
 
@@ -23,6 +28,7 @@ CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 def enable_compile_cache() -> str:
     """Point JAX's persistent compilation cache at its one directory and
     return that directory."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

@@ -189,22 +189,24 @@ def _sample_and_forward(params, images, key, chip_ids, planes, *, det_cfg,
     `committee_wave_forward`: rebuild each group's `MappedLayer` from the
     hoisted planes/meta, sample the chunk's `DetectorEnsemble` in-trace, and
     run the ensemble structural forward.  Keeping ONE body guarantees the
-    serving wave traces the exact ops of the MC chunk program per lane."""
+    serving wave traces the exact ops of the MC chunk program per lane.
+    The sampling runs under the named scope `sample`."""
     from repro.core.mapping import MappedLayer
     from repro.models.detector import IRCDetector
     det = IRCDetector(det_cfg, spec)
     layers: Dict[str, Tuple[ChipEnsemble, ...]] = {}
-    for layer_planes, (name, layer_id, gmeta) in zip(planes, meta):
-        groups = []
-        for g, ((gp, gn), (bias_rows, scheme, fan_in)) in enumerate(
-                zip(layer_planes, gmeta)):
-            mapped = MappedLayer(g_pos=gp, g_neg=gn, bias_rows=bias_rows,
-                                 scheme=scheme, fan_in=fan_in)
-            keys = detector_layer_keys(key, chip_ids, layer_id, g)
-            groups.append(sample_ensemble_with_keys(
-                keys, mapped, chip_ids=chip_ids, cfg=cfg_ni, spec=spec,
-                device=device))
-        layers[name] = tuple(groups)
+    with jax.named_scope("sample"):
+        for layer_planes, (name, layer_id, gmeta) in zip(planes, meta):
+            groups = []
+            for g, ((gp, gn), (bias_rows, scheme, fan_in)) in enumerate(
+                    zip(layer_planes, gmeta)):
+                mapped = MappedLayer(g_pos=gp, g_neg=gn, bias_rows=bias_rows,
+                                     scheme=scheme, fan_in=fan_in)
+                keys = detector_layer_keys(key, chip_ids, layer_id, g)
+                groups.append(sample_ensemble_with_keys(
+                    keys, mapped, chip_ids=chip_ids, cfg=cfg_ni, spec=spec,
+                    device=device))
+            layers[name] = tuple(groups)
     ens = DetectorEnsemble(layers=layers, chip_ids=chip_ids)
     return det.apply(params, images, mode="ensemble", ensemble=ens,
                      cfg_ni=cfg_ni, sa_extra=sa_extra,
@@ -314,6 +316,12 @@ def run_mc_detector(key: jax.Array, det, params, images: jax.Array,
     a run directory; `stderr_target` stops at the first chunk boundary where
     the mAP standard error reaches the target — identical moments to the
     same-length prefix of the full run (same engine semantics as `run_mc`).
+
+    In a profiler trace the pipelined loop names its host phases:
+    `repro.mc.planes` (`detector_planes`, once per call),
+    `repro.mc.dispatch` (each chunk's dispatch, argument `chunk`),
+    `repro.mc.wait` (the `device_s` lap) and `repro.mc.score` (the
+    `host_s` lap: fetch and mAP scoring).
     """
     from repro.train.det_loss import evaluate_map_per_chip
 
@@ -335,15 +343,17 @@ def run_mc_detector(key: jax.Array, det, params, images: jax.Array,
                  for lo in range(0, mc.n_chips, mc.chunk_size)]
 
     if pipeline:
-        planes, meta = detector_planes(det, params)
+        with jax.profiler.TraceAnnotation("repro.mc.planes"):
+            planes, meta = detector_planes(det, params)
 
-        def dispatch(ids):
-            """Launch one chunk's sample+forward on device, without waiting."""
-            return _sampled_chunk_forward(
-                params, images, key, ids, planes, det_cfg=det.cfg,
-                spec=det.spec, cfg_ni=mc.cfg, sa_extra=sa_extra, meta=meta,
-                use_kernel=use_kernel, kernel_impl=kernel_impl,
-                device=mc.device)
+        def dispatch(i):
+            """Launch chunk `i`'s sample+forward on device, without waiting."""
+            with jax.profiler.TraceAnnotation("repro.mc.dispatch", chunk=i):
+                return _sampled_chunk_forward(
+                    params, images, key, chunk_ids[i], planes,
+                    det_cfg=det.cfg, spec=det.spec, cfg_ni=mc.cfg,
+                    sa_extra=sa_extra, meta=meta, use_kernel=use_kernel,
+                    kernel_impl=kernel_impl, device=mc.device)
 
     inflight = None
     n_done = 0
@@ -351,15 +361,15 @@ def run_mc_detector(key: jax.Array, det, params, images: jax.Array,
         n_chunk = int(ids.shape[0])
         with timer.lap(items=n_chunk):
             if pipeline:
-                with dev_timer.lap(items=n_chunk):
+                with dev_timer.lap(items=n_chunk, span="repro.mc.wait"):
                     if inflight is None:
                         # first chunk: its jit compile is part of the
                         # first (compile) lap
-                        inflight = dispatch(ids)
+                        inflight = dispatch(chunk_i)
                     preds_dev = jax.block_until_ready(inflight)
                 if chunk_i + 1 < len(chunk_ids):
                     # double buffer: next chunk on device DURING host scoring
-                    inflight = dispatch(chunk_ids[chunk_i + 1])
+                    inflight = dispatch(chunk_i + 1)
             else:
                 with dev_timer.lap(items=n_chunk):
                     ens = build_detector_ensemble(key, det, params,
@@ -370,7 +380,7 @@ def run_mc_detector(key: jax.Array, det, params, images: jax.Array,
                         cfg_ni=mc.cfg, sa_extra=sa_extra,
                         use_kernel=use_kernel, kernel_impl=kernel_impl,
                         device=mc.device))
-            with host_timer.lap(items=n_chunk):
+            with host_timer.lap(items=n_chunk, span="repro.mc.score"):
                 preds = np.asarray(preds_dev)
                 vals = jnp.asarray(evaluate_map_per_chip(
                     preds, gt_boxes, gt_classes, det.cfg.n_anchors,

@@ -294,7 +294,9 @@ class McResult:
     pipelined sweep the next chunk runs on device DURING the host slice, so
     blocked time collapses; `1 - device_s / wall_s` measures the realized
     overlap (serial loop ~= host fraction; -> 1.0 as device waits are fully
-    hidden behind host scoring).
+    hidden behind host scoring).  Both are host-clock times: `device_s` is
+    how long the host was blocked on the device, not the device's own
+    compute time, which only a profiler trace gives.
     """
     n_chips: int
     metrics: Dict[str, Dict[str, float]]      # name -> {mean,std,qXX,...}
@@ -303,7 +305,7 @@ class McResult:
     chips_per_sec: float
     compile_s: float = 0.0
     bias_units: Optional[np.ndarray] = None   # per-chip calibrated bias
-    device_s: float = 0.0                     # blocked-on-device wall
+    device_s: float = 0.0                     # host blocked on device
     host_s: float = 0.0                       # host-side metric wall
 
     def summary_line(self, metric: str = "bit_agreement") -> str:

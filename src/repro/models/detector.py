@@ -509,38 +509,47 @@ class IRCDetector:
         the backend they were sampled with, so pass the same backend here.
         The `mode="train"` noise surrogate stays analytic by design — it is
         a calibrated QAT proxy, not a physics path.
+
+        The stem, each block `s{s}b{b}` (with its widening concat), each
+        stage's max pool `s{s}pool` and the head run under `jax.named_scope`s
+        of those names, which a device trace carries in each op's `tf_op`.
         """
         cfg = self.cfg
         key = key if key is not None else jax.random.PRNGKey(0)
-        x = self.stem(params, images, mode=mode)
+        with jax.named_scope("stem"):
+            x = self.stem(params, images, mode=mode)
 
         for s, (ch, nb) in enumerate(zip(cfg.stage_channels,
                                          cfg.blocks_per_stage)):
             c_in = cfg.stage_channels[max(0, s - 1)] if s else ch
             for b in range(nb):
+                name = f"s{s}b{b}"
                 cin = c_in if b == 0 else ch
-                if cin < ch:   # widen by repetition before the block
-                    x = jnp.concatenate([x] * (ch // cin), axis=-1)
-                    cin = ch
-                if mode == "ensemble":
-                    x = self._gconv_ensemble(
-                        ensemble.layers[f"s{s}b{b}"], x, cin, ch,
-                        cfg_ni=cfg_ni, sa_extra=sa_extra,
-                        use_kernel=use_kernel, kernel_impl=kernel_impl,
-                        device=device)
-                elif mode == "train_ensemble":
-                    x = self._gconv_train_ensemble(
-                        params[f"s{s}b{b}"], ensemble.layers[f"s{s}b{b}"],
-                        x, cin, ch, key=jax.random.fold_in(key, s * 10 + b),
-                        cfg_ni=cfg_ni, use_kernel=use_kernel,
-                        kernel_impl=kernel_impl, device=device)
-                else:
-                    x = self._gconv(params[f"s{s}b{b}"], x, cin, ch,
-                                    mode=mode,
-                                    key=jax.random.fold_in(key, s * 10 + b),
-                                    cfg_ni=cfg_ni, sa_extra=sa_extra,
-                                    device=device)
-            wd = (1,) * (x.ndim - 3) + (2, 2, 1)
-            x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, wd, wd,
-                                      "SAME")
-        return x @ params["head"] + params["head_b"]
+                with jax.named_scope(name):
+                    if cin < ch:   # widen by repetition before the block
+                        x = jnp.concatenate([x] * (ch // cin), axis=-1)
+                        cin = ch
+                    if mode == "ensemble":
+                        x = self._gconv_ensemble(
+                            ensemble.layers[name], x, cin, ch,
+                            cfg_ni=cfg_ni, sa_extra=sa_extra,
+                            use_kernel=use_kernel, kernel_impl=kernel_impl,
+                            device=device)
+                    elif mode == "train_ensemble":
+                        x = self._gconv_train_ensemble(
+                            params[name], ensemble.layers[name], x, cin, ch,
+                            key=jax.random.fold_in(key, s * 10 + b),
+                            cfg_ni=cfg_ni, use_kernel=use_kernel,
+                            kernel_impl=kernel_impl, device=device)
+                    else:
+                        x = self._gconv(params[name], x, cin, ch, mode=mode,
+                                        key=jax.random.fold_in(key,
+                                                               s * 10 + b),
+                                        cfg_ni=cfg_ni, sa_extra=sa_extra,
+                                        device=device)
+            with jax.named_scope(f"s{s}pool"):
+                wd = (1,) * (x.ndim - 3) + (2, 2, 1)
+                x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, wd, wd,
+                                          "SAME")
+        with jax.named_scope("head"):
+            return x @ params["head"] + params["head_b"]

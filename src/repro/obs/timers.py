@@ -39,11 +39,20 @@ class PhaseTimer:
         self.last_s = 0.0
 
     @contextlib.contextmanager
-    def lap(self, items: float = 0.0):
+    def lap(self, items: float = 0.0, span: Optional[str] = None):
+        """Time one lap; with `span`, the lap is also a host span of that
+        name in a running profiler trace (`jax.profiler.TraceAnnotation`),
+        so the trace and the timer measure the same interval."""
+        if span is None:
+            annotation = contextlib.nullcontext()
+        else:
+            from jax.profiler import TraceAnnotation
+            annotation = TraceAnnotation(span)
         t0 = time.perf_counter()
         handle = _Lap(items)
         try:
-            yield handle
+            with annotation:
+                yield handle
         finally:
             dt = time.perf_counter() - t0
             self.last_s = dt

@@ -264,7 +264,8 @@ class DetectorServeEngine:
                 nxt = self._collect_wave(block=False)
                 # double buffer: next wave on device DURING host decode
                 inflight = self._dispatch(nxt) if nxt else None
-                with self.host_timer.lap(items=len(wave)):
+                with self.host_timer.lap(items=len(wave),
+                                         span="repro.serve.decode"):
                     responses = self._complete(wave, preds)
             self._log_wave(responses)
             served += len(wave)
@@ -337,7 +338,11 @@ class DetectorServeEngine:
 
     def stats(self) -> Dict[str, Dict[str, float]]:
         """Phase summaries (first-wave compile split from steady-state
-        requests/sec) plus queue-latency percentiles."""
+        requests/sec) plus queue-latency percentiles.  All are host-clock
+        times: "device" is how long the host was blocked waiting for each
+        wave's committee forward, not the device's own compute time;
+        "host" is the wave decode (`_complete`, the profiler span
+        `repro.serve.decode`)."""
         return {"wave": self.wave_timer.summary(),
                 "device": self.dev_timer.summary(),
                 "host": self.host_timer.summary(),

@@ -160,6 +160,11 @@ def make_det_qat_step(det, *, train_chips: int = 1,
     quantized weights, chip identity frozen between `ens_key` changes), runs
     `mode="train_ensemble"`, and averages the loss over chip realizations by
     folding the chips axis into the batch.
+
+    The step's phases run under named scopes that a device trace carries
+    in each op's `tf_op`: `train_planes` (the deviation population), the
+    detector's own (`stem`, `s{s}b{b}`, `s{s}pool`, `head`), `loss` and
+    `adamw`; the backward pass of each appears as `transpose(jvp(<scope>))`.
     """
     from repro.core import nonideal as ni
     from repro.train.det_loss import yolo_loss
@@ -172,21 +177,26 @@ def make_det_qat_step(det, *, train_chips: int = 1,
             if train_chips == 1:
                 pred = det.apply(p, images, mode="train", key=key,
                                  cfg_ni=cfg_ni)
-                return yolo_loss(pred, targets, det.cfg.n_anchors,
-                                 det.cfg.n_classes)
+                with jax.named_scope("loss"):
+                    return yolo_loss(pred, targets, det.cfg.n_anchors,
+                                     det.cfg.n_classes)
             from repro.mc.detector_mc import build_train_ensemble
-            ens = build_train_ensemble(ens_key, det, p, train_chips,
-                                       cfg=cfg_ni)
+            with jax.named_scope("train_planes"):
+                ens = build_train_ensemble(ens_key, det, p, train_chips,
+                                           cfg=cfg_ni)
             pred = det.apply(p, images, mode="train_ensemble", key=key,
                              cfg_ni=cfg_ni, ensemble=ens)
-            pred = pred.reshape((-1,) + pred.shape[2:])   # chips into batch
-            tiled = jax.tree.map(
-                lambda t: jnp.tile(t, (train_chips,) + (1,) * (t.ndim - 1)),
-                targets)
-            return yolo_loss(pred, tiled, det.cfg.n_anchors,
-                             det.cfg.n_classes)
+            with jax.named_scope("loss"):
+                pred = pred.reshape((-1,) + pred.shape[2:])  # chips to batch
+                tiled = jax.tree.map(
+                    lambda t: jnp.tile(t, (train_chips,)
+                                       + (1,) * (t.ndim - 1)),
+                    targets)
+                return yolo_loss(pred, tiled, det.cfg.n_anchors,
+                                 det.cfg.n_classes)
         (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        params, opt, _ = adamw_update(grads, opt, params, lr, opt_cfg)
+        with jax.named_scope("adamw"):
+            params, opt, _ = adamw_update(grads, opt, params, lr, opt_cfg)
         return params, opt, loss
 
     return qat_step

@@ -29,15 +29,29 @@ jax.jit(probe)(jnp.arange(7.0)).block_until_ready()
 """
 
 
-def _run(env_extra, drop=()):
+_SCOPED = """
+import sys
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import enable_compile_cache
+enable_compile_cache()
+def probe(x):
+    with jax.named_scope(sys.argv[2]):
+        return jnp.sin(x) * 3.0 + 1.0
+probe.__name__ = sys.argv[1]
+jax.jit(probe)(jnp.arange(7.0)).block_until_ready()
+print(0, 0)
+"""
+
+
+def _run(env_extra, drop=(), name=None, program=_PROGRAM, args=()):
     """Compile one uniquely named function in a fresh interpreter; return
     (cache dir returned, cache dir configured, function name)."""
-    name = f"cache_probe_{uuid.uuid4().hex}"
+    name = name or f"cache_probe_{uuid.uuid4().hex}"
     env = {k: v for k, v in os.environ.items() if k not in drop}
     env.update(PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu",
                JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0", **env_extra)
-    r = subprocess.run([sys.executable, "-c", _PROGRAM, name], env=env,
-                       capture_output=True, text=True, timeout=120)
+    r = subprocess.run([sys.executable, "-c", program, name, *args],
+                       env=env, capture_output=True, text=True, timeout=120)
     assert r.returncode == 0, r.stderr[-2000:]
     returned, configured = r.stdout.split()
     return returned, configured, name
@@ -66,3 +80,15 @@ def test_default_is_fixed_checkout_dir():
     finally:
         for p in mine:
             p.unlink()
+
+
+def test_named_scopes_are_part_of_the_key(tmp_path):
+    """The same program twice shares one entry; under another named scope
+    it gets its own, so a profiler trace shows the scopes of the build that
+    ran, never those of a build whose executable was cached."""
+    cache = tmp_path / "cache"
+    name = f"cache_probe_{uuid.uuid4().hex}"
+    for scope in ("stem", "stem", "head"):
+        _run({"JAX_COMPILATION_CACHE_DIR": str(cache)}, name=name,
+             program=_SCOPED, args=(scope,))
+    assert len(_entries(cache, name)) == 2
