@@ -55,6 +55,20 @@ def _block_reduce(x_ext: jax.Array, plane: jax.Array, block: int
                       precision=jax.lax.Precision.HIGHEST)
 
 
+def _chunk_bounds(nb: int, accumulation: str, partial_rows: int,
+                  block: int) -> Tuple[Tuple[int, int], ...]:
+    """Static (lo, hi) block ranges whose currents accumulate together:
+    the whole line for "single_shot"; runs of `partial_rows // block`
+    blocks for "partial_sum", the last one short where they do not divide
+    nb."""
+    if accumulation == "single_shot":
+        return ((0, nb),)
+    if accumulation == "partial_sum":
+        k = max(1, partial_rows // block)
+        return tuple((lo, min(lo + k, nb)) for lo in range(0, nb, k))
+    raise ValueError(f"unknown accumulation mode: {accumulation}")
+
+
 def _accumulate(blocks: jax.Array, counts: jax.Array, cfg: ni.NonidealConfig,
                 spec: MacroSpec, accumulation: str, partial_rows: int,
                 device=None) -> Tuple[jax.Array, jax.Array]:
@@ -62,36 +76,67 @@ def _accumulate(blocks: jax.Array, counts: jax.Array, cfg: ni.NonidealConfig,
 
     blocks/counts: [..., nb, N] (currents with variation / ideal LRS counts).
     Returns (bit-line current [..., N], activated LRS count [..., N]).
-    `device` routes the IR-drop factors through a `repro.device` backend
-    (None: the analytic linear wire model, bit-identical).
+
+    With IR drop on the analytic periphery (`device` None or one whose
+    `analytic_periphery` holds) the dropped currents of the line or of
+    each partial-sum chunk come from `ni.ir_dropped_currents`, one pass over
+    the blocks under the `ir_drop` scope.  A backend with its own periphery
+    weights the blocks by its `ir_drop_factors` hook instead.  Chunk c of a
+    partial sum is blocks [c*k, min((c+1)*k, nb)), k = partial_rows //
+    ir_block; each chunk, or the single-shot line, then sees the
+    nonlinearity of its own activated-LRS count.
     """
-    if cfg.ir_drop:
-        blocks = blocks * ni._device_or_analytic(device).ir_drop_factors(
-            blocks, spec, axis=-2)
+    chunks = _chunk_bounds(blocks.shape[-2], accumulation, partial_rows,
+                           spec.ir_block)
+    dev = ni._device_or_analytic(device)
     p_total = jnp.sum(counts, axis=-2)
-    if accumulation == "single_shot":
-        i_line = jnp.sum(blocks, axis=-2)
-        if cfg.nonlinearity:
-            i_line = ni.apply_nonlinearity(i_line, p_total)
-    elif accumulation == "partial_sum":
-        nb = blocks.shape[-2]
-        chunk = max(1, partial_rows // spec.ir_block)
-        n_chunks = -(-nb // chunk)
-        pad = n_chunks * chunk - nb
-        if pad:
-            zeros = [(0, 0)] * blocks.ndim
-            zeros[-2] = (0, pad)
-            blocks = jnp.pad(blocks, zeros)
-            counts = jnp.pad(counts, zeros)
-        cshape = blocks.shape[:-2] + (n_chunks, chunk, blocks.shape[-1])
-        i_chunk = jnp.sum(blocks.reshape(cshape), axis=-2)
-        p_chunk = jnp.sum(counts.reshape(cshape), axis=-2)
-        if cfg.nonlinearity:
-            i_chunk = ni.apply_nonlinearity(i_chunk, p_chunk)
-        i_line = jnp.sum(i_chunk, axis=-2)
-    else:
-        raise ValueError(f"unknown accumulation mode: {accumulation}")
-    return i_line, p_total
+    p_chunks = [p_total]
+    if cfg.nonlinearity and len(chunks) > 1:
+        p_chunks = [_block_sum(counts, lo, hi) for lo, hi in chunks]
+    if not (cfg.ir_drop and dev.analytic_periphery):
+        if cfg.ir_drop:
+            blocks = blocks * dev.ir_drop_factors(blocks, spec, axis=-2)
+        if len(chunks) == 1:
+            i_chunks = [jnp.sum(blocks, axis=-2)]
+        else:
+            i_chunks = [_block_sum(blocks, lo, hi) for lo, hi in chunks]
+        return _line_current(i_chunks, p_chunks, cfg), p_total
+    if len(p_chunks) > 1:
+        p_total = _add(p_chunks)     # whole-number counts: exact in any order
+    # The barriers give the counts, each plane's pass over its blocks and
+    # the SA epilogue fusions of their own.  Fused together, both planes'
+    # block currents are live at once, the count blocks are read twice and
+    # the pass's scalar constants become full-size operands.
+    p_total, p_chunks = jax.lax.optimization_barrier((p_total, p_chunks))
+    with jax.named_scope("ir_drop"):
+        dropped = ni.ir_dropped_currents(blocks, spec.ir_alpha)
+        i_line = _line_current([_add(dropped[lo:hi]) for lo, hi in chunks],
+                               p_chunks, cfg)
+    return jax.lax.optimization_barrier(i_line), p_total
+
+
+def _add(terms):
+    """Left-to-right sum of a sequence of arrays."""
+    acc = terms[0]
+    for t in terms[1:]:
+        acc = acc + t
+    return acc
+
+
+def _block_sum(x: jax.Array, lo: int, hi: int) -> jax.Array:
+    """x[..., lo:hi, :] summed over the block axis, as adds of static
+    slices: elementwise, so it fuses with its consumers."""
+    return _add([x[..., b, :] for b in range(lo, hi)])
+
+
+def _line_current(i_chunks, p_chunks, cfg: ni.NonidealConfig) -> jax.Array:
+    """The bit-line current from its chunks' currents: with the
+    nonlinearity each chunk (the whole line in single shot) is distorted by
+    its own activated-LRS count before the chunks add up."""
+    if cfg.nonlinearity:
+        i_chunks = [ni.apply_nonlinearity(i, p)
+                    for i, p in zip(i_chunks, p_chunks)]
+    return _add(i_chunks)
 
 
 def sample_chip_planes(key: jax.Array, g_pos: jax.Array, g_neg: jax.Array,

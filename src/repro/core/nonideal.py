@@ -119,13 +119,18 @@ def ir_drop_factors(block_currents: jax.Array, alpha: float,
 
     The cumulative wire drop is LINEAR in the block currents:
       cum_wire[b] = sum_{k=1..b} suffix_sum(I)_k = sum_j min(b, j) * I_j,
-    so it is one [nb, nb] min-matrix contraction instead of the
-    reverse-cumsum-cumsum chain (XLA lowers cumsum miserably on CPU, the
-    flips/transposes cost full passes over the [chips, batch, blocks, cols]
-    tensors of `repro.mc`, and Pallas TPU has no `rev`).  The min matrix is
-    symmetric, so each layout is a plain matmul; the Pallas kernel calls
-    this function on its [nb, bn] tile slabs.  The `axis` argument lets
-    batched callers keep the block axis wherever their layout puts it.
+    so here it is one [nb, nb] min-matrix contraction: Pallas TPU has no
+    `rev`, and the fused kernel (`repro.kernels.irc_mvm`) and its oracle
+    call this function on their [nb, bn] tile slabs.  This is the
+    definition of the factors, and the path of a `repro.device` backend
+    whose own `ir_drop_factors` hook replaces it.  The structural
+    simulation on the analytic periphery does not materialize the factors:
+    `ir_dropped_currents` computes the same sums by the suffix/prefix
+    recurrence, in one pass over the block currents (on a v5e this dot at
+    `HIGHEST` is six bf16 passes and moves several times the bytes of the
+    block currents it weights).  The min matrix is symmetric, so each
+    layout is a plain matmul.  The `axis` argument lets batched callers
+    keep the block axis wherever their layout puts it.
 
     The contraction runs at `Precision.HIGHEST`: block currents carry
     variation-scaled conductances, and a default-precision TPU matmul would
@@ -145,6 +150,34 @@ def ir_drop_factors(block_currents: jax.Array, alpha: float,
         moved = jnp.moveaxis(block_currents, ax, -1)
         cum = jnp.moveaxis(mm(moved, w_min), -1, ax)
     return jnp.clip(1.0 - alpha * cum, 0.0, 1.0)
+
+
+def ir_dropped_currents(block_currents: jax.Array, alpha: float) -> list:
+    """IR-dropped current of every block along a bit-line, in one pass.
+
+    `block_currents` [..., nb, N] are the ideal per-block currents, block 0
+    first, ordered as in `ir_drop_factors`.  Returns the nb [..., N]
+    currents I_b * clip(1 - alpha * W_b, 0, 1), where the wire drop W_b is
+    the cum_wire[b] that `ir_drop_factors` defines, built by a recurrence
+    over the static block slices: the suffix current S_b = I_b + S_{b+1}
+    from the far end, then W_0 = 0 and W_b = W_{b-1} + S_b.  Each term is
+    float32 elementwise work on [..., N] slices, so XLA fuses the pass, and
+    the sums its caller takes of the terms, into one loop that reads the
+    block currents once; no [nb, nb] dot and no factor or cumulative
+    tensor is materialized.  Only the order of the float32 additions
+    differs from `block_currents * ir_drop_factors(block_currents)`.
+    """
+    nb = block_currents.shape[-2]
+    cur = [block_currents[..., b, :] for b in range(nb)]
+    suffix = cur[:]
+    for b in range(nb - 2, -1, -1):
+        suffix[b] = cur[b] + suffix[b + 1]
+    out = [cur[0]]                         # W_0 = 0: block 0 keeps it all
+    wire = None
+    for b in range(1, nb):
+        wire = suffix[b] if wire is None else wire + suffix[b]
+        out.append(cur[b] * jnp.clip(1.0 - alpha * wire, 0.0, 1.0))
+    return out
 
 
 def apply_ir_drop(block_currents: jax.Array, alpha: float) -> jax.Array:

@@ -81,7 +81,8 @@ def _line_currents(blocks, line, params: IrcEpilogueParams) -> None:
     """Bit-line currents of one (bm, bn) tile from its per-block currents
     `blocks` (NBT, bm, bn), written into `line` (bm, bn).  With IR drop each
     tile row's [NBT, bn] slab is weighted by `ir_drop_factors` — the
-    min-matrix contraction the structural simulation uses."""
+    min-matrix contraction that defines the drop (the structural simulation
+    computes the same sums in one pass, `ni.ir_dropped_currents`)."""
     if not params.apply_ir:
         line[...] = jnp.sum(blocks[...], axis=0)
         return

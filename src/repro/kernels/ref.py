@@ -74,7 +74,8 @@ def _line_current(x: jax.Array, eplane: jax.Array, ep_: IrcEpilogueParams
     block size; appended zero rows sit at the far end of the bit-line and
     carry no current, so the drop factors of real blocks are unchanged.
     The drop factors come from `repro.core.nonideal.ir_drop_factors`, the
-    one source the kernel and the structural simulation also use."""
+    definition the kernel also uses (the structural simulation computes the
+    same sums in one pass, `ir_dropped_currents`)."""
     pad = (-x.shape[1]) % ep_.ir_block
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad)))

@@ -1,5 +1,8 @@
 """Tests for quantizers, mapping, crossbar forward, and calibration
 (paper Secs. IV-B, Table I) — the system invariants the paper argues for."""
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +14,10 @@ from repro.core import (MacroSpec, NonidealConfig,
                         extend_inputs, fold_bn_to_bias_units,
                         crossbar_forward, ideal_ternary_matmul,
                         IRCLinear, IRCLinearConfig,
-                        calibrate_bias, sa_error_rates, layer_current_stats)
+                        calibrate_bias, sa_error_rates, layer_current_stats,
+                        DEFAULT_MACRO, apply_nonlinearity, ir_drop_factors)
+from repro.core.crossbar import _accumulate
+from repro.device import AnalyticDeviceModel
 
 
 class TestQuantizers:
@@ -165,6 +171,102 @@ class TestCrossbarForward:
         b = crossbar_forward(jax.random.PRNGKey(9), x, ternary_planes(w, 32),
                              cfg=NonidealConfig.all())
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _paper_blocks(nb: int, seed: int = 0):
+    """Block currents and LRS counts of a few bit-lines at the paper's
+    scale: up to 32 activated cells per 32-row block under log-normal
+    variation, so a few to a few hundred units per line."""
+    k_c, k_v = jax.random.split(jax.random.PRNGKey(seed))
+    counts = jax.random.randint(k_c, (3, 16, nb, 60), 0, 33
+                                ).astype(jnp.float32)
+    blocks = counts * jnp.exp(DEFAULT_MACRO.sigma_lrs
+                              * jax.random.normal(k_v, counts.shape))
+    return blocks, counts
+
+
+def _factor_path(blocks, counts, cfg, accumulation, partial_rows):
+    """The line current by the definition: blocks weighted by
+    `ir_drop_factors`, summed per partial-sum chunk or over the line."""
+    nb = blocks.shape[-2]
+    dropped = blocks * ir_drop_factors(blocks, DEFAULT_MACRO.ir_alpha,
+                                       axis=-2)
+    k = nb if accumulation == "single_shot" else (
+        partial_rows // DEFAULT_MACRO.ir_block)
+    total = 0.0
+    for lo in range(0, nb, k):
+        i = jnp.sum(dropped[..., lo:lo + k, :], axis=-2)
+        if cfg.nonlinearity:
+            i = apply_nonlinearity(i, jnp.sum(counts[..., lo:lo + k, :],
+                                              axis=-2))
+        total = total + i
+    return total
+
+
+class TestIrDropAccumulation:
+    @pytest.mark.parametrize("nb", [18, 20])
+    @pytest.mark.parametrize("nonlinearity", [False, True],
+                             ids=["linear", "nonlinear"])
+    @pytest.mark.parametrize("accumulation", ["single_shot", "partial_sum"])
+    def test_one_pass_equals_factor_path(self, accumulation, nonlinearity,
+                                         nb):
+        """The one-pass recurrence gives the factor path's line current to
+        float32 rounding; at nb 20 and 212-row chunks the last chunk holds
+        2 blocks of 6."""
+        blocks, counts = _paper_blocks(nb)
+        cfg = NonidealConfig(ir_drop=True, nonlinearity=nonlinearity)
+        i_line, p_total = _accumulate(blocks, counts, cfg, DEFAULT_MACRO,
+                                      accumulation, 212)
+        want = _factor_path(blocks, counts, cfg, accumulation, 212)
+        assert float(jnp.max(want)) > 100.0
+        np.testing.assert_allclose(np.asarray(i_line), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(p_total),
+                                      np.asarray(jnp.sum(counts, axis=-2)))
+
+    def test_backend_with_own_periphery_keeps_its_hook(self):
+        """A backend that clears `analytic_periphery` and brings its own
+        IR-drop factors is weighted by them, not by the analytic pass."""
+        calls = []
+
+        @dataclasses.dataclass(frozen=True)
+        class HalfDrop(AnalyticDeviceModel):
+            name = "half-drop"
+
+            @property
+            def analytic_periphery(self):
+                return False
+
+            def ir_drop_factors(self, block_currents, spec=DEFAULT_MACRO,
+                                axis=-1):
+                calls.append(axis)
+                return jnp.full_like(block_currents, 0.5)
+
+        blocks, counts = _paper_blocks(18, seed=1)
+        i_line, _ = _accumulate(blocks, counts, NonidealConfig(ir_drop=True),
+                                DEFAULT_MACRO, "single_shot", 256,
+                                device=HalfDrop())
+        assert calls == [-2]
+        np.testing.assert_allclose(np.asarray(i_line),
+                                   np.asarray(0.5 * jnp.sum(blocks, axis=-2)),
+                                   rtol=1e-6)
+
+    @pytest.mark.parametrize("accumulation,rows", [("single_shot", 572),
+                                                   ("partial_sum", 636)])
+    def test_no_block_square_dot(self, accumulation, rows):
+        """At the paper's stage-0 shapes (4 dies x 147456 positions x 60
+        columns) the IR-dropped accumulation lowers to elementwise work:
+        no dot takes an [nb, nb] operand."""
+        nb = -(-rows // DEFAULT_MACRO.ir_block)
+        f32 = lambda *d: jax.ShapeDtypeStruct(d, jnp.float32)
+        acc = jax.jit(lambda b, c: _accumulate(
+            b, c, NonidealConfig.all(), DEFAULT_MACRO, accumulation, 212))
+        text = acc.lower(f32(4, 147456, nb, 60),
+                         f32(4, 147456, nb, 60)).as_text(debug_info=True)
+        square = re.compile(rf"tensor<{nb}x{nb}xf32>")
+        dots = [ln for ln in text.splitlines() if "dot_general" in ln]
+        assert not [ln for ln in dots if square.search(ln)]
+        assert "ir_drop" in text
 
 
 class TestCalibration:

@@ -65,7 +65,8 @@ def test_chunk_program_carries_every_stage_scope():
         jax.random.PRNGKey(1), jnp.arange(2, dtype=jnp.uint32), planes,
         det_cfg=det.cfg, spec=det.spec, cfg_ni=NonidealConfig.all(),
         sa_extra=0.0, meta=meta, use_kernel=False).compile().as_text()
-    assert _detector_scopes(det.cfg) | {"sample"} <= _scopes_in(text)
+    assert (_detector_scopes(det.cfg) | {"sample", "ir_drop"}
+            <= _scopes_in(text))
 
 
 def test_qat_step_carries_every_stage_and_step_scope():
